@@ -19,28 +19,6 @@ def _row(name, us, derived):
     sys.stdout.flush()
 
 
-def enable_compile_cache() -> str:
-    """Point jax at a persistent on-disk compilation cache.
-
-    The vectorized sim's XLA compiles (~1.5s-15s each, BENCH_sim.json
-    compile_cold_s) dominate short benches; with the cache they amortise
-    across processes/CI runs (compile_warm_s).  Safe to call before any
-    jax computation; returns the cache dir.
-    """
-    cache_dir = os.environ.get(
-        "JAX_COMPILATION_CACHE_DIR",
-        os.path.join(os.path.dirname(os.path.abspath(__file__)),
-                     ".jax_cache"))
-    import jax
-    jax.config.update("jax_compilation_cache_dir", cache_dir)
-    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
-    try:
-        jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
-    except Exception:
-        pass                      # older jax: size gate stays at default
-    return cache_dir
-
-
 def bench_table6_overhead():
     from repro.sim.experiments import table6_overhead
     t0 = time.time()
@@ -684,19 +662,15 @@ def main() -> None:
     jax_tier = {"sim-vector", "engine", "kernels"}
     targets = args.targets or [t for t in named
                                if not (args.skip_engine and t in jax_tier)]
-    # fig6/fig7 default to the vector engine (with a scalar fallback on
-    # numpy-only interpreters), so they benefit from the cache too — but
-    # must not make a bare interpreter crash here
+    # fig6/fig7 run the vector engine, so they share the cache too
     if any(t in jax_tier or t in ("fig6", "fig7") for t in targets):
-        try:
-            # multi-controller sweeps on CPU-only hosts: split the host
-            # into 4 devices BEFORE the backend initializes (no-op when
-            # XLA_FLAGS already forces a count, e.g. in CI)
-            from repro.sim.sweeps import force_host_devices
-            force_host_devices(4)
-            enable_compile_cache()
-        except ImportError:
-            pass                  # numpy-only: scalar fallbacks still run
+        from repro.launch.compile_cache import enable_compile_cache
+        from repro.sim.sweeps import force_host_devices
+        # multi-controller sweeps on a CPU host: split it into 4 devices
+        # BEFORE the backend initializes (no-op on the chip, or when
+        # XLA_FLAGS already forces a count, e.g. in CI)
+        force_host_devices(4)
+        enable_compile_cache()
     for t in targets:
         if t not in named:
             raise SystemExit(f"unknown bench target {t!r}; "

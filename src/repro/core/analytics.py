@@ -113,6 +113,24 @@ def summarize_batch(samples):
     }
 
 
+def fixed_order_sum(x):
+    """Sum of a 1-D float array in one fixed pairwise order.
+
+    ``jnp.sum`` leaves the order of its additions to the compiler, which
+    on the TPU chooses it by the shape of the whole program: a sweep's
+    means came out different for a config summed in a vmapped batch of 8
+    on one chip and of 2 on each of four.  Halving with elementwise adds
+    fixes the order, so the summaries are bit-identical across device
+    counts.
+    """
+    import jax.numpy as jnp
+    n = 1 << max(0, (x.size - 1).bit_length())
+    x = jnp.concatenate([x, jnp.zeros(n - x.size, x.dtype)])
+    while x.size > 1:
+        x = x[:x.size // 2] + x[x.size // 2:]
+    return x[0]
+
+
 def summarize_masked_batch(samples, ok):
     """Success-conditioned :func:`summarize_batch`, safe under jit/vmap.
 
@@ -143,8 +161,9 @@ def summarize_masked_batch(samples, ok):
         w = (idx - lo).astype(s.dtype)
         return jnp.where(n_ok > 0, s[lo] * (1 - w) + s[hi] * w, nan)
 
-    mean = jnp.where(n_ok > 0, jnp.sum(jnp.where(m, a, 0.0)) / denom, nan)
-    var = jnp.sum(jnp.where(m, (a - mean) ** 2, 0.0)) / denom
+    mean = jnp.where(n_ok > 0, fixed_order_sum(jnp.where(m, a, 0.0)) / denom,
+                     nan)
+    var = fixed_order_sum(jnp.where(m, (a - mean) ** 2, 0.0)) / denom
     return {
         "mean": mean,
         "median": q(50.0),

@@ -24,8 +24,6 @@ from typing import Any
 import jax
 import jax.numpy as jnp
 
-from repro.models.moe import shard_map  # version-portable wrapper
-
 P = jax.sharding.PartitionSpec
 
 
@@ -107,10 +105,10 @@ def speculative_apply(fn, mesh, flight_axis: str, value_spec, *,
         return adopted, winner
 
     def wrapped(*args):
-        return shard_map(
-            member_fn, mesh,
+        return jax.shard_map(
+            member_fn, mesh=mesh,
             in_specs=tuple(P() for _ in args),
-            out_specs=(value_spec, P()),
+            out_specs=(value_spec, P()), check_vma=False,
         )(*args)
 
     return wrapped

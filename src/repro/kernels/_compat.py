@@ -1,25 +1,21 @@
-"""Pallas API compatibility across the jax versions this repo sees.
-
-The kernels target the current Pallas TPU API (``pltpu.CompilerParams``);
-older jax releases (<= 0.4.x) expose the same dataclass as
-``pltpu.TPUCompilerParams``.  Resolve once here so every kernel tier stays
-importable on both, instead of each kernel carrying its own getattr dance.
-"""
+"""Where the sim-side Pallas kernels run compiled and where interpreted."""
 from __future__ import annotations
-
-from jax.experimental.pallas import tpu as pltpu
-
-CompilerParams = getattr(pltpu, "CompilerParams", None) or getattr(
-    pltpu, "TPUCompilerParams")
 
 
 def interpret_default() -> bool:
     """Whether Pallas calls should default to interpret mode here.
 
-    Compiled Pallas targets the TPU backend; everywhere else (CPU CI
-    runners, forced-host device meshes, local dev boxes) the same kernels
-    run through the Pallas interpreter so the code path stays exercised.
-    Ops with an ``interpret=None`` knob resolve it through this one gate.
+    Compiled on the TPU; interpreted on the CPU, where tests and CI run
+    the same kernels through the Pallas interpreter.  Any other backend
+    is an error: silently interpreting on an accelerator would measure
+    the interpreter, not the kernel.  Ops with an ``interpret=None`` knob
+    resolve it through this one gate.
     """
     import jax
-    return jax.default_backend() != "tpu"
+    backend = jax.default_backend()
+    if backend == "tpu":
+        return False
+    if backend == "cpu":
+        return True
+    raise RuntimeError(f"the Pallas kernels compile for the TPU only; "
+                       f"backend {backend!r} would run them interpreted")

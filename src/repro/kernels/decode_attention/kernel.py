@@ -16,8 +16,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels._compat import CompilerParams
-
 NEG_INF = -2.3819763e38
 
 
@@ -93,7 +91,7 @@ def decode_attention(q, k, v, kv_pos, *, scale: float | None = None,
             pltpu.VMEM((hq, 1), jnp.float32),
             pltpu.VMEM((hq, d), jnp.float32),
         ],
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
     )(q, k, v, kv_pos)

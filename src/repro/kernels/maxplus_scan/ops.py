@@ -1,10 +1,10 @@
 """Jitted wrapper for the max-plus summary-scan kernel.
 
 ``interpret=None`` resolves through ``kernels._compat.interpret_default``
-(compiled on TPU backends, Pallas interpreter everywhere else) so the
-same call site — including ``QueueFlightSim(summary_backend="pallas")``
-via ``scan_core.maxplus_prefix_entries`` — runs on CPU CI and on
-accelerators unchanged.
+(compiled on TPU, the Pallas interpreter on CPU) so the same call site —
+including ``QueueFlightSim(summary_backend="pallas")`` via
+``scan_core.maxplus_prefix_entries`` — runs on CPU CI and on the chip
+unchanged.
 """
 from __future__ import annotations
 

@@ -17,8 +17,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels._compat import CompilerParams
-
 
 def _kernel(a_ref, w_ref, o_ref, acc_ref, *, num_k: int):
     kd = pl.program_id(3)
@@ -61,7 +59,7 @@ def expert_matmul(buf, w, *, block_c: int = 128, block_f: int = 128,
                                lambda ie, ic, jf, kd: (ie, ic, jf)),
         out_shape=jax.ShapeDtypeStruct((e, c, f), buf.dtype),
         scratch_shapes=[pltpu.VMEM((block_c, block_f), jnp.float32)],
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel",
                                  "arbitrary")),
         interpret=interpret,

@@ -14,6 +14,13 @@ best-fit/earliest-free key as ``scan_core.bestfit_book_step``, and one
 Grid: (trials, num_blocks), blocks sequential innermost.  One-hot
 row/column selects only (no dynamic loads/stores inside the loop) — the
 same discipline the jnp engines use, and what the TPU vector unit wants.
+
+Layout: every operand is viewed as (T, 1, ·) with the trial dimension
+squeezed out of the block, so each block's last two dimensions are
+(1, B) / (1, W) — equal to the array's own (1, ·) row.  Mosaic requires
+a block's last two dimensions to be (8, 128)-divisible or whole; a (1, B)
+block on a (T, N) array is neither.  B must be a multiple of 128 lanes
+(or the whole stream).
 """
 from __future__ import annotations
 
@@ -24,8 +31,6 @@ import jax.numpy as jnp
 from jax import lax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
-
-from repro.kernels._compat import CompilerParams
 
 
 def _kernel(wf0_ref, r_ref, s_ref, fin_ref, st_ref, wk_ref, wf_out_ref,
@@ -51,15 +56,15 @@ def _kernel(wf0_ref, r_ref, s_ref, fin_ref, st_ref, wk_ref, wf_out_ref,
         # -wf; -max(key) is the booking-delay floor (scan_core's step)
         key = jnp.where(wf <= r_i, wf, -wf)
         kmax = jnp.max(key)
-        w = jnp.argmax(key)
+        # the first worker attaining the max, as XLA's argmax picks it
+        w = jnp.min(jnp.where(key == kmax, wcol, W))
         st_i = jnp.maximum(r_i, -kmax)
         f_i = st_i + s_i
         w_hot = wcol == w
         wf2 = jnp.where(w_hot & live, f_i, wf)
         fin2 = jnp.where(sel, jnp.where(live, f_i, jnp.inf), fin)
         st2 = jnp.where(sel, jnp.where(live, st_i, jnp.inf), st)
-        wk2 = jnp.where(sel, jnp.where(live, w.astype(jnp.int32),
-                                       jnp.int32(-1)), wk)
+        wk2 = jnp.where(sel, jnp.where(live, w, jnp.int32(-1)), wk)
         return wf2, fin2, st2, wk2
 
     wf, fin, st, wk = lax.fori_loop(
@@ -77,7 +82,7 @@ def _kernel(wf0_ref, r_ref, s_ref, fin_ref, st_ref, wk_ref, wf_out_ref,
         wf_out_ref[...] = wf
 
 
-def queue_booking(ready, service, wf0, *, block: int = 64,
+def queue_booking(ready, service, wf0, *, block: int = 128,
                   interpret: bool = False):
     """ready/service: (T, N) ready-sorted event streams (N a multiple of
     ``block``; pad with ready=inf, service=0 — dead events book nothing);
@@ -87,34 +92,29 @@ def queue_booking(ready, service, wf0, *, block: int = 64,
     """
     T, N = ready.shape
     W = wf0.shape[1]
-    assert N % block == 0, (N, block)
+    if N % block or (block % 128 and block != N):
+        raise ValueError(f"block {block} must divide the stream length {N} "
+                         "and be a multiple of 128 lanes (or all of it)")
     nb = N // block
 
+    row = pl.BlockSpec((pl.Squeezed(), 1, block), lambda it, ib: (it, 0, ib))
+    pool = pl.BlockSpec((pl.Squeezed(), 1, W), lambda it, ib: (it, 0, 0))
     kernel = functools.partial(_kernel, num_blocks=nb, block=block, W=W)
     fin, st, wk, wf = pl.pallas_call(
         kernel,
         grid=(T, nb),
-        in_specs=[
-            pl.BlockSpec((1, W), lambda it, ib: (it, 0)),
-            pl.BlockSpec((1, block), lambda it, ib: (it, ib)),
-            pl.BlockSpec((1, block), lambda it, ib: (it, ib)),
-        ],
-        out_specs=[
-            pl.BlockSpec((1, block), lambda it, ib: (it, ib)),
-            pl.BlockSpec((1, block), lambda it, ib: (it, ib)),
-            pl.BlockSpec((1, block), lambda it, ib: (it, ib)),
-            pl.BlockSpec((1, W), lambda it, ib: (it, 0)),
-        ],
+        in_specs=[pool, row, row],
+        out_specs=[row, row, row, pool],
         out_shape=[
-            jax.ShapeDtypeStruct((T, N), jnp.float32),
-            jax.ShapeDtypeStruct((T, N), jnp.float32),
-            jax.ShapeDtypeStruct((T, N), jnp.int32),
-            jax.ShapeDtypeStruct((T, W), jnp.float32),
+            jax.ShapeDtypeStruct((T, 1, N), jnp.float32),
+            jax.ShapeDtypeStruct((T, 1, N), jnp.float32),
+            jax.ShapeDtypeStruct((T, 1, N), jnp.int32),
+            jax.ShapeDtypeStruct((T, 1, W), jnp.float32),
         ],
         scratch_shapes=[pltpu.VMEM((1, W), jnp.float32)],
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
-    )(wf0.astype(jnp.float32), ready.astype(jnp.float32),
-      service.astype(jnp.float32))
-    return fin, st, wk, wf
+    )(wf0.astype(jnp.float32)[:, None], ready.astype(jnp.float32)[:, None],
+      service.astype(jnp.float32)[:, None])
+    return fin[:, 0], st[:, 0], wk[:, 0], wf[:, 0]

@@ -18,8 +18,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels._compat import CompilerParams
-
 
 def _kernel(x_ref, dt_ref, a_ref, b_ref, c_ref, y_ref, st_out_ref, state_ref,
             *, num_chunks: int):
@@ -103,7 +101,7 @@ def ssd_scan(x, dt, A, B, C, *, chunk: int = 256, interpret: bool = False):
             jax.ShapeDtypeStruct((b, h, p, n), jnp.float32),
         ],
         scratch_shapes=[pltpu.VMEM((p, n), jnp.float32)],
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(x.astype(jnp.float32), dt.astype(jnp.float32), A.astype(jnp.float32),

@@ -13,14 +13,9 @@ import jax
 
 
 def make_mesh(shape, axes):
-    """jax.make_mesh across jax versions: ``axis_types`` (and the
-    ``jax.sharding.AxisType`` enum) only exist on newer releases; older ones
-    default every axis to auto sharding anyway, so simply omit the kwarg."""
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is not None:
-        return jax.make_mesh(shape, axes,
-                             axis_types=(axis_type.Auto,) * len(axes))
-    return jax.make_mesh(shape, axes)
+    """``jax.make_mesh`` with every axis under automatic sharding."""
+    return jax.make_mesh(shape, axes,
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):
@@ -35,8 +30,8 @@ def make_config_mesh(devices=None):
     ``sim/sweeps.py`` shards config-grid sweeps over this mesh; on CPU-only
     hosts the devices come from ``--xla_force_host_platform_device_count``
     (``sim.sweeps.force_host_devices``), so the same code path runs on a
-    multi-chip pod and a GitHub runner.  Built from an explicit device list
-    (``jax.make_mesh`` has no devices knob on older releases).
+    multi-chip host and a GitHub runner.  Built from an explicit device
+    list, so a sweep can run on a prefix of the devices.
     """
     import numpy as np
     devs = list(devices) if devices is not None else jax.devices()

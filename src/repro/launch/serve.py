@@ -10,6 +10,7 @@ the live streaming scheduler service.
 from __future__ import annotations
 
 import argparse
+import json
 import sys
 
 import jax
@@ -98,12 +99,14 @@ def _run_generate(args) -> int:
 
 
 def _run_scheduler(args) -> int:
+    from repro.launch.compile_cache import enable_compile_cache
     from repro.serving.engine import SchedulerService
     from repro.sim.events import (DiurnalArrivals, MMPPArrivals,
                                   PoissonArrivals)
     from repro.sim.vector_queue import (QueueFlightSim, heavytail_queue,
                                         keygen_queue, thumbnail_queue,
                                         wordcount_queue)
+    cache_dir = enable_compile_cache()
     wl = {"keygen": keygen_queue, "wordcount": wordcount_queue,
           "thumbnail": thumbnail_queue, "heavytail": heavytail_queue}[
               args.workload]()
@@ -114,17 +117,33 @@ def _run_scheduler(args) -> int:
             "diurnal": lambda: DiurnalArrivals(sim.rate_hz, seed=args.seed),
             }[args.arrival]()
     svc = SchedulerService(sim, microbatch=args.microbatch, seed=args.seed)
-    rep = svc.run_open_load(jobs=args.jobs, microbatch=args.microbatch,
-                            slo_ms=args.slo_ms, process=proc,
-                            seed=args.seed)
+    dev = jax.devices()[0]
+    blk, res, scan = svc.config
+    print(f"device {dev.platform} {dev.device_kind} x{jax.device_count()}; "
+          f"engine block={blk} resolver={res} scan={scan}; "
+          f"compile cache {cache_dir}")
+    cold_s = svc.warmup()
+    jax.clear_caches()            # warm = the same compile, from the cache
+    warm_s = svc.warmup()
+    rep = svc.run_open_load(jobs=args.jobs, slo_ms=args.slo_ms,
+                            process=proc, warmup=False)
     print(f"{args.workload} @ {args.load} ({args.arrival} arrivals, "
-          f"{sim.W} workers/{sim.A} AZs):")
+          f"{sim.W} workers/{sim.A} AZs, flight {sim.flight}):")
+    print(f"  compile cold {cold_s:.2f} s, warm {warm_s:.2f} s")
     print(f"  sustained {rep.jobs_per_s:,.0f} jobs/s "
           f"({rep.jobs} jobs in {rep.wall_s*1e3:.0f} ms wall)")
     print(f"  sojourn mean {rep.mean_ms:.0f} ms  p50 {rep.p50_ms:.0f} ms  "
           f"p99 {rep.p99_ms:.0f} ms")
     print(f"  SLO {rep.slo_ms:.0f} ms violated "
           f"{rep.slo_violation_frac*100:.1f}% (ok {rep.ok_frac*100:.1f}%)")
+    # the whole report as one JSON line, last, for scripts
+    print(json.dumps(dict(
+        rep.summary(), workload=args.workload, load=args.load,
+        arrival=args.arrival, workers=sim.W, azs=sim.A, flight=sim.flight,
+        microbatch=args.microbatch, block=blk, resolver=res, scan=scan,
+        compile_cold_s=cold_s, compile_warm_s=warm_s,
+        platform=dev.platform, device_kind=dev.device_kind,
+        device_count=jax.device_count())))
     return 0
 
 

@@ -29,19 +29,6 @@ import jax.numpy as jnp
 from repro.configs.base import MoEConfig
 from repro.models.layers import mlp_block
 
-try:
-    from jax import shard_map as _shard_map  # jax >= 0.7 stable API
-
-    def shard_map(f, mesh, in_specs, out_specs):
-        return _shard_map(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                          check_vma=False)
-except ImportError:  # pragma: no cover - older jax
-    from jax.experimental.shard_map import shard_map as _shard_map_old
-
-    def shard_map(f, mesh, in_specs, out_specs):
-        return _shard_map_old(f, mesh=mesh, in_specs=in_specs,
-                              out_specs=out_specs, check_rep=False)
-
 P = jax.sharding.PartitionSpec
 
 
@@ -191,12 +178,12 @@ def moe_block_ep(x, p, moe: MoEConfig, mlp_variant: str, ep: EPSpec, *,
                                  tiled=True)
         return _combine_local(out, routing, topw_l, xt_l.shape[0], d, xt_l.dtype)
 
-    y = shard_map(
-        local_fn, ep.mesh,
+    y = jax.shard_map(
+        local_fn, mesh=ep.mesh,
         in_specs=(P(token_axes, None), P(token_axes, None),
                   P(token_axes, None),
                   P(ma, None, None), P(ma, None, None), P(ma, None, None)),
-        out_specs=P(token_axes, None),
+        out_specs=P(token_axes, None), check_vma=False,
     )(xt, topw, topi, wg, wu, wd)
 
     if moe.shared_expert_ff:

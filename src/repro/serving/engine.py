@@ -248,9 +248,17 @@ class SchedulerService:
                  pipeline_depth: int = 2, seed: Optional[int] = None):
         from repro.sim.streaming import StreamingScheduler
         self.sim = sim
+        self.microbatch = microbatch
+        self.pipeline_depth = pipeline_depth
+        self.seed = seed
         self.engine = StreamingScheduler(
             sim, microbatch=microbatch, pipeline_depth=pipeline_depth,
             seed=seed)
+
+    @property
+    def config(self):
+        """The resolved (block, resolver, scan) the service books with."""
+        return self.engine.config
 
     def submit(self, arrivals_ms) -> None:
         self.engine.submit(arrivals_ms)
@@ -258,8 +266,20 @@ class SchedulerService:
     def drain(self):
         return self.engine.drain()
 
+    def warmup(self) -> float:
+        """Compile the service's executables on a scratch engine; returns
+        the wall seconds (see :func:`repro.sim.streaming.warm_up`)."""
+        from repro.sim.streaming import warm_up
+        return warm_up(self.sim, microbatch=self.microbatch,
+                       pipeline_depth=self.pipeline_depth, seed=self.seed)
+
     def run_open_load(self, **kw):
+        """Sustained open load at this service's microbatch, pipeline
+        depth and seed (keyword arguments override them)."""
         from repro.sim.streaming import run_open_load
+        kw.setdefault("microbatch", self.microbatch)
+        kw.setdefault("pipeline_depth", self.pipeline_depth)
+        kw.setdefault("seed", self.seed)
         return run_open_load(self.sim, **kw)
 
 

@@ -106,11 +106,7 @@ def fig6_scale_effect(seed: int = 0, duration_s: float = 1800.0,
     """
     out = {}
     if engine == "vector":
-        try:
-            from repro.sim.vector_queue import keygen_queue, load_sweep
-        except ImportError:       # numpy-only interpreter: scalar oracle
-            engine = "scalar"
-    if engine == "vector":
+        from repro.sim.vector_queue import keygen_queue, load_sweep
         for name, dep in (("one_az_5w", LOW_AVAIL), ("three_az_15w", HA)):
             n = jobs if jobs is not None else max(256, int(
                 rate_for(keygen_workload(), dep, "medium") * duration_s))
@@ -151,13 +147,8 @@ def fig7_other_workloads(seed: int = 0, duration_s: float = 1800.0,
     the scalar oracle's discipline; tests/test_sim_queue.py).
     """
     if engine == "vector":
-        try:
-            from repro.sim.vector_queue import (QueueFlightSim,
-                                                thumbnail_queue,
-                                                wordcount_queue)
-        except ImportError:       # numpy-only interpreter: scalar oracle
-            return fig7_other_workloads(seed=seed, duration_s=duration_s,
-                                        engine="scalar", load=load)
+        from repro.sim.vector_queue import (QueueFlightSim, thumbnail_queue,
+                                            wordcount_queue)
         out = {}
         for name, qwl in (("wordcount", wordcount_queue()),
                           ("thumbnail", thumbnail_queue())):

@@ -14,7 +14,8 @@ runs it through ``shard_map`` over the 1-D ``("config",)`` mesh
 bit-identical to the single-device one (tests/test_sweeps.py) — donates the
 stacked per-config input buffers on accelerator backends, and shares the
 jitted-runner cache across plans (plus the persistent XLA compile cache,
-``benchmarks.run.enable_compile_cache``, for the cross-process case).
+``repro.launch.compile_cache.enable_compile_cache``, for the cross-process
+case).
 
 Multi-controller on CPU hosts: :func:`force_host_devices` forces
 ``--xla_force_host_platform_device_count`` before the jax backend
@@ -46,14 +47,6 @@ from repro.sim.vector import (VectorResult, VectorWorkload, _raptor_sweep_core,
 # CPU fallback: force a host-device mesh before the backend initializes
 # --------------------------------------------------------------------------
 
-def _backend_live() -> bool:
-    try:
-        from jax._src import xla_bridge
-        return bool(xla_bridge._backends)
-    except Exception:   # registry moved (newer jax): assume live -> no-op
-        return True
-
-
 def force_host_devices(n: int) -> int:
     """Ensure the process sees >= ``n`` devices by forcing XLA's host-
     platform device count — the CPU fallback for the multi-controller sweep
@@ -65,15 +58,20 @@ def force_host_devices(n: int) -> int:
     device-count flag is respected as-is.  If the backend is already live
     and sees fewer than ``n`` devices, the request cannot take effect —
     that raises a clear ``RuntimeError`` instead of silently running the
-    sweep unsharded.  Returns the live device count, so callers size
-    their shard axis on the actual value, never the requested one.
+    sweep unsharded.  The flag splits only the host CPU: on an
+    accelerator backend the devices are the chips, whatever ``n`` says.
+    Returns the live device count, so callers size their shard axis on
+    the actual value, never the requested one.
     """
+    from jax._src import xla_bridge
     flag = "--xla_force_host_platform_device_count"
     user_set = flag in os.environ.get("XLA_FLAGS", "")
-    if not user_set and not _backend_live():
+    if not user_set and not xla_bridge.backends_are_initialized():
         os.environ["XLA_FLAGS"] = (
             os.environ.get("XLA_FLAGS", "") + f" {flag}={int(n)}").strip()
-    elif not user_set and jax.device_count() < int(n):
+    if jax.default_backend() != "cpu":
+        return jax.device_count()
+    if not user_set and jax.device_count() < int(n):
         raise RuntimeError(
             f"force_host_devices({n}) called after the jax backend "
             f"initialized with {jax.device_count()} device(s); call it "
@@ -122,11 +120,14 @@ def _sharded_runner(core, devices):
     """
     fn = jax.vmap(core, in_axes=(None, 0, None))
     if len(devices) > 1:
-        from jax.experimental.shard_map import shard_map
         P = jax.sharding.PartitionSpec
-        fn = shard_map(fn, mesh=make_config_mesh(devices),
-                       in_specs=(P(), P("config"), P()),
-                       out_specs=P("config"))
+        # check_vma=False: the per-config event scans carry values that
+        # become device-varying inside the scan (their inputs are sharded
+        # config rows), which the varying-axes check rejects as a carry
+        # type change; the body is pure batching, so nothing is lost
+        fn = jax.shard_map(fn, mesh=make_config_mesh(devices),
+                           in_specs=(P(), P("config"), P()),
+                           out_specs=P("config"), check_vma=False)
     # donating the stacked config buffers is free on accelerators — run()
     # passes per-dispatch copies, never the plan's own arrays, exactly so
     # they are safe to donate; the CPU runtime ignores donation with a

@@ -19,7 +19,6 @@ SCRIPT = textwrap.dedent("""
     from jax.sharding import PartitionSpec as P
     from repro.core.jaxops import first_finisher, k_of_n_mean, masked_mean
     from repro.launch.mesh import make_mesh
-    from repro.models.moe import shard_map
 
     mesh = make_mesh((4, 2), ("pod", "model"))
 
@@ -30,8 +29,9 @@ SCRIPT = textwrap.dedent("""
 
     lats = jnp.array([3.0, 1.0, 2.0, 5.0])
     vals = jnp.arange(4 * 6, dtype=jnp.float32).reshape(4, 6)  # per-pod rows
-    f = shard_map(member, mesh, in_specs=(P("pod"), P("pod", None)),
-                  out_specs=(P("pod", None), P("pod")))
+    f = jax.shard_map(member, mesh=mesh,
+                      in_specs=(P("pod"), P("pod", None)),
+                      out_specs=(P("pod", None), P("pod")), check_vma=False)
     adopted, winner = jax.jit(f)(lats, vals)
     a = np.asarray(adopted)
     assert np.all(np.asarray(winner) == 1), winner
@@ -44,8 +44,9 @@ SCRIPT = textwrap.dedent("""
         return m, jnp.broadcast_to(n, (1,))
 
     health = jnp.array([1.0, 0.0, 1.0, 1.0])
-    f2 = shard_map(member2, mesh, in_specs=(P("pod"), P("pod", None)),
-                   out_specs=(P("pod", None), P("pod")))
+    f2 = jax.shard_map(member2, mesh=mesh,
+                       in_specs=(P("pod"), P("pod", None)),
+                       out_specs=(P("pod", None), P("pod")), check_vma=False)
     m, n = jax.jit(f2)(health, vals)
     expect = np.asarray(vals)[[0, 2, 3]].mean(axis=0)
     np.testing.assert_allclose(np.asarray(m)[0], expect, rtol=1e-6)
@@ -55,8 +56,9 @@ SCRIPT = textwrap.dedent("""
     def member3(lat, val):
         return k_of_n_mean(val, lat[0], 2, "pod")
 
-    f3 = shard_map(member3, mesh, in_specs=(P("pod"), P("pod", None)),
-                   out_specs=P("pod", None))
+    f3 = jax.shard_map(member3, mesh=mesh,
+                       in_specs=(P("pod"), P("pod", None)),
+                       out_specs=P("pod", None), check_vma=False)
     km = jax.jit(f3)(lats, vals)
     expect = np.asarray(vals)[[1, 2]].mean(axis=0)   # lats 1.0 and 2.0
     np.testing.assert_allclose(np.asarray(km)[0], expect, rtol=1e-6)

@@ -287,3 +287,87 @@ def test_stock_open_sojourns_dep_free_only():
                           load="low", seed=1)
     with pytest.raises(ValueError, match="dep-free"):
         stock_open_sojourns(wsim, arr)
+
+
+# ---------------------------------------------------------------------------
+# the service face and its launcher
+# ---------------------------------------------------------------------------
+
+def test_scheduler_service_runs_at_its_own_settings():
+    """run_open_load books at the service's microbatch and seed, not at
+    fresh defaults: the same load through the module driver at those
+    settings gives the same sojourns."""
+    from repro.serving.engine import SchedulerService
+    sim = QueueFlightSim(keygen_queue(), num_workers=12, num_azs=3,
+                         load="medium", seed=1)
+    svc = SchedulerService(sim, microbatch=24, pipeline_depth=1, seed=7)
+    got = svc.run_open_load(jobs=200, warmup=False,
+                            process=PoissonArrivals(sim.rate_hz, seed=3))
+    want = run_open_load(sim, jobs=200, microbatch=24, pipeline_depth=1,
+                         seed=7, warmup=False,
+                         process=PoissonArrivals(sim.rate_hz, seed=3))
+    other = run_open_load(sim, jobs=200, warmup=False,
+                          process=PoissonArrivals(sim.rate_hz, seed=3))
+    assert (got.mean_ms, got.p99_ms) == (want.mean_ms, want.p99_ms)
+    assert got.mean_ms != other.mean_ms
+    assert svc.config == sim.engine_config("raptor")
+
+
+@pytest.fixture
+def cache_config():
+    """Restore the persistent-cache settings the launcher turns on, so no
+    later test in this process writes a cache."""
+    names = ("jax_compilation_cache_dir",
+             "jax_persistent_cache_min_compile_time_secs",
+             "jax_persistent_cache_min_entry_size_bytes")
+    saved = {n: getattr(jax.config, n) for n in names}
+    yield
+    for n, v in saved.items():
+        jax.config.update(n, v)
+
+
+def test_launcher_scheduler_mode_reports_device_and_config(capsys,
+                                                         cache_config):
+    """The device and the resolved engine config come before the numbers;
+    the last line is the whole report as JSON."""
+    import json
+
+    from repro.launch import serve
+    from repro.sim.vector_queue import auto_config
+    assert serve.main(["--mode", "scheduler", "--workload", "keygen",
+                       "--workers", "9", "--azs", "3", "--jobs", "128",
+                       "--microbatch", "32", "--arrival", "mmpp"]) == 0
+    lines = capsys.readouterr().out.strip().splitlines()
+    dev = jax.devices()[0]
+    assert lines[0].startswith(
+        f"device {dev.platform} {dev.device_kind} x{jax.device_count()}")
+    blk, res, scan = auto_config("raptor")
+    assert f"block={blk} resolver={res} scan={scan}" in lines[0]
+    rep = json.loads(lines[-1])
+    assert rep["jobs"] == 128 and rep["microbatch"] == 32
+    assert (rep["block"], rep["resolver"], rep["scan"]) == (blk, res, scan)
+    assert rep["platform"] == dev.platform and rep["flight"] == 2
+    assert rep["compile_cold_s"] > 0 and rep["compile_warm_s"] > 0
+    assert rep["p50_ms"] <= rep["p99_ms"]
+
+
+@pytest.mark.parametrize("env_dir", [None, "given"])
+def test_compile_cache_dir(monkeypatch, tmp_path, env_dir, cache_config):
+    """JAX_COMPILATION_CACHE_DIR wins and no other directory is set in
+    code; without it the cache is the fixed path inside the checkout."""
+    from repro.launch.compile_cache import (CHECKOUT_CACHE_DIR,
+                                            enable_compile_cache)
+    if env_dir:
+        # as JAX itself does when the variable is set at start-up
+        given = str(tmp_path / env_dir)
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", given)
+        jax.config.update("jax_compilation_cache_dir", given)
+        assert enable_compile_cache() == given
+        assert jax.config.jax_compilation_cache_dir == given
+    else:
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+        assert enable_compile_cache() == str(CHECKOUT_CACHE_DIR)
+        assert jax.config.jax_compilation_cache_dir == str(
+            CHECKOUT_CACHE_DIR)
+        assert (CHECKOUT_CACHE_DIR.parent / "chip_smoke.py").exists()
+    assert jax.config.jax_persistent_cache_min_compile_time_secs == 0.0
