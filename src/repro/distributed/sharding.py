@@ -43,7 +43,7 @@ class Plan:
     data: Any = None          # filled in __post_init__
     model: str = "model"
     zero3: bool = True        # shard params+opt state over data axis
-    # §Perf variants (benchmarks/hillclimb.py):
+    # §Perf variants:
     seq_parallel: Optional[bool] = None  # residual sharded over model on seq;
     # None = auto: ON for archs whose head count doesn't divide the model
     # axis (measured 2-2.5x on the collective term, EXPERIMENTS.md §Perf)

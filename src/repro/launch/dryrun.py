@@ -98,7 +98,7 @@ def lower_cell(cfg: ModelConfig, shape: ShapeConfig, mesh):
 
 def collective_bytes(hlo_text: str) -> Dict[str, float]:
     """Sum per-device operand bytes of collective ops in SPMD HLO, with ring
-    cost factors applied later (benchmarks/roofline.py)."""
+    cost factors left to the caller."""
     out: Dict[str, float] = {}
     # lines look like: %all-reduce.5 = bf16[1024,512]{1,0} all-reduce(...)
     for m in re.finditer(

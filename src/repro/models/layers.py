@@ -9,8 +9,7 @@ that are visible to ``compiled.cost_analysis()`` wherever possible:
 - ``attention_blockwise`` : flash-style running-softmax scan over KV chunks.
                             Used for 32k global-attention prefill.  The scan
                             body is counted ONCE by cost_analysis; the known
-                            trip count is corrected analytically in
-                            benchmarks/roofline.py.
+                            trip count must be corrected for analytically.
 - ``attention_sliding_blocked`` : sliding-window attention computed on
                             (block, 2*window) tiles with no scan — exact for
                             local layers and fully FLOP-visible.

@@ -284,7 +284,7 @@ def sweep_scale(trials: int = 20000, seed: int = 0, devices=None) -> Dict:
     # AZ sweep 1→8 (flight of 4) and flight sweep 2→16 (8 AZs): the whole
     # grid runs pad-and-masked through sweep_pairs — flight size and AZ
     # count are traced, so the curves share a handful of compilations
-    # instead of paying one (~1.5s, BENCH_sim.json) per point
+    # instead of paying one per point
     az_points = [dict(flight=4, num_azs=a) for a in (1, 2, 3, 4, 6, 8)]
     fl_points = [dict(flight=f, num_azs=8) for f in (2, 4, 8, 16)]
     wl = exponential_vector(2, 1000.0)

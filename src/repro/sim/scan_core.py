@@ -55,6 +55,8 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
+from repro.core.obs import stage
+
 
 def booking_contrib(num_workers: int, widx, rel):
     """Dense (..., W) max-map of one event's bookings.
@@ -64,24 +66,27 @@ def booking_contrib(num_workers: int, widx, rel):
     no worker and contributes ``-inf`` everywhere.  One-hot arithmetic
     only — per-trial dynamic scatters cripple the vmapped replay on CPU.
     """
-    oh = widx[..., None] == jnp.arange(num_workers)
-    return jnp.max(jnp.where(oh, rel[..., None], -jnp.inf), axis=-2)
+    with stage("booking"):
+        oh = widx[..., None] == jnp.arange(num_workers)
+        return jnp.max(jnp.where(oh, rel[..., None], -jnp.inf), axis=-2)
 
 
 def apply_bookings(wf, widx, rel):
     """Fold one event's bookings into the free-at vector (max-plus)."""
-    return jnp.maximum(wf, booking_contrib(wf.shape[-1], widx, rel))
+    with stage("booking"):
+        return jnp.maximum(wf, booking_contrib(wf.shape[-1], widx, rel))
 
 
 def exclusive_running_max(contrib, wf_in):
     """Per-event observed W-vectors: row ``i`` is ``max(wf_in,
     max_{j<i} contrib[j])`` — the worker vector event ``i`` would see had
     events ``0..i-1`` booked exactly ``contrib[0..i-1]``."""
-    run = lax.cummax(contrib, axis=0)
-    prev = jnp.concatenate(
-        [jnp.full((1,) + run.shape[1:], -jnp.inf, run.dtype), run[:-1]],
-        axis=0)
-    return jnp.maximum(wf_in[None, :], prev)
+    with stage("booking"):
+        run = lax.cummax(contrib, axis=0)
+        prev = jnp.concatenate(
+            [jnp.full((1,) + run.shape[1:], -jnp.inf, run.dtype), run[:-1]],
+            axis=0)
+        return jnp.maximum(wf_in[None, :], prev)
 
 
 # --------------------------------------------------------------------------
@@ -170,7 +175,10 @@ def _fixpoint_resolver(body, W):
     event's irrelevant worker pick may flap between passes without ever
     changing what any event observes, and conversely equal bookings under
     unequal observations would exit with stale outputs.  The returned
-    ``(est, out)`` are always evaluated at the converged rows."""
+    ``(est, out)`` are always evaluated at the converged rows; ``p`` is
+    the number of passes the block took, in ``[1, nev]`` (under ``vmap``
+    each lane keeps its own count: the batched loop runs until the slowest
+    lane converges and selects per lane)."""
     vbody = jax.vmap(body)
 
     def resolve(wf, ev):
@@ -185,16 +193,17 @@ def _fixpoint_resolver(body, W):
 
         def cond(c):
             p, rows, used = c[0], c[1], c[2]
-            return jnp.any(rows != used) & (p < nev)
+            with stage("booking"):
+                return jnp.any(rows != used) & (p < nev)
 
         def again(c):
             p, rows = c[0], c[1]
             est2, out2 = vbody(rows, ev)
             return p + 1, rows_of(est2), rows, est2, out2
 
-        _, _, _, est, out = lax.while_loop(
+        p, _, _, est, out = lax.while_loop(
             cond, again, (jnp.asarray(1), rows_of(est1), rows0, est1, out1))
-        return est, out
+        return est, out, p
 
     return resolve
 
@@ -211,7 +220,7 @@ def _unrolled_resolver(body, unroll=None):
 
         _, (est, out) = lax.scan(
             step, wf, ev, unroll=nev if unroll is None else min(unroll, nev))
-        return est, out
+        return est, out, None
 
     return resolve
 
@@ -274,7 +283,10 @@ def blocked_event_replay(body, wf0, events, *, block: int,
 
     Every (resolver, scan) configuration is bitwise-identical to the
     ``block=1`` oracle scan (tests/test_queue_properties.py).  Returns
-    ``(wf_final, outs)`` with each out leaf stacked along the event axis.
+    ``((wf_final, passes), outs)`` with each out leaf stacked along the
+    event axis.  ``passes`` is the fixpoint resolver's pass count per
+    block, (blocks,) int32 with a ragged tail's last, on the ``"seq"``
+    chain; ``None`` on every other path.
     """
     W = int(wf0.shape[-1])
     n = int(jax.tree_util.tree_leaves(events)[0].shape[0])
@@ -293,8 +305,9 @@ def blocked_event_replay(body, wf0, events, *, block: int,
         def step(wf, ev):
             (widx, rel), out = body(wf, ev)
             return apply_bookings(wf, widx, rel), out
-        return lax.scan(step, wf0, events,
-                        unroll=unroll if block <= 1 else block)
+        wf_r, outs = lax.scan(step, wf0, events,
+                              unroll=unroll if block <= 1 else block)
+        return (wf_r, None), outs
 
     if resolver == "fixpoint":
         resolve = _fixpoint_resolver(body, W)
@@ -315,12 +328,15 @@ def blocked_event_replay(body, wf0, events, *, block: int,
             if rem else None)
 
     def resolve_step(wf, ev):
-        est, out = resolve(wf, ev)
-        return jnp.maximum(wf, jnp.max(booking_contrib(W, *est), axis=0)), out
+        est, out, p = resolve(wf, ev)
+        with stage("booking"):
+            wf = jnp.maximum(wf, jnp.max(booking_contrib(W, *est), axis=0))
+        return wf, (out, p)
 
+    passes = None
     if scan == "seq":
         if nb:
-            wf_r, outs = lax.scan(resolve_step, wf0, main)
+            wf_r, (outs, passes) = lax.scan(resolve_step, wf0, main)
             outs = jax.tree_util.tree_map(
                 lambda a: a.reshape((split,) + a.shape[2:]), outs)
         else:
@@ -334,9 +350,12 @@ def blocked_event_replay(body, wf0, events, *, block: int,
         else:
             wf_r, outs = wf0, None
     if rem:
-        wf_r, out_t = resolve_step(wf_r, tail)
+        wf_r, (out_t, p_t) = resolve_step(wf_r, tail)
         outs = out_t if outs is None else _tree_concat(outs, out_t)
-    return wf_r, outs
+        if scan == "seq":
+            passes = (p_t[None] if passes is None
+                      else jnp.concatenate([passes, p_t[None]]))
+    return (wf_r, passes), outs
 
 
 def _logdepth_replay(resolve, wf0, ev_blocks, nb, W, summary_backend,
@@ -362,7 +381,7 @@ def _logdepth_replay(resolve, wf0, ev_blocks, nb, W, summary_backend,
                                       interpret=interpret)
 
     entries0 = jnp.broadcast_to(wf0, (nb, W))
-    est0, out0 = vres(entries0, ev_blocks)
+    est0, out0, _ = vres(entries0, ev_blocks)
     entries1, wf1 = prefix(est0)
 
     def cond(c):
@@ -371,7 +390,7 @@ def _logdepth_replay(resolve, wf0, ev_blocks, nb, W, summary_backend,
 
     def again(c):
         p, entries = c[0], c[1]
-        est, out = vres(entries, ev_blocks)
+        est, out, _ = vres(entries, ev_blocks)
         entries2, wf2 = prefix(est)
         return p + 1, entries2, entries, est, out, wf2
 

@@ -21,7 +21,7 @@ Multi-controller on CPU hosts: :func:`force_host_devices` forces
 ``--xla_force_host_platform_device_count`` before the jax backend
 initializes, splitting the host into N devices so the sharded path runs —
 and is CI-tested — on a plain GitHub runner.  The closed-loop grids shard
-near-linearly (BENCH_sim.json ``sweep_sharded``): their event scans are
+near-linearly on a host: their event scans are
 tiny-op dispatch-bound work XLA cannot intra-op-parallelize, exactly the
 coordinator fan-out Wukong/Archipelago get their wins from.  The open-loop
 cores are wide elementwise batches that already saturate a host's cores on
@@ -315,7 +315,7 @@ def _queue_raptor_core(jobs, W, A, F, graph, dist, fail_prob,
     def core(keys, cfg, shared):
         rate, oh_mu, oh_sigma = cfg
         rho, means, offset, cv, stage_oh, slat = shared
-        resp, ok = jax.vmap(trial, in_axes=(0,) + (None,) * 9)(
+        resp, ok, _ = jax.vmap(trial, in_axes=(0,) + (None,) * 9)(
             keys, rate, rho, means, offset, cv, stage_oh, slat,
             oh_mu, oh_sigma)
         return summarize_masked_batch(resp, ok)

@@ -19,8 +19,8 @@ utilisation) also runs on-device.  Config sweeps are batched in both
 tiers and routed through the device-sharded driver in
 :mod:`repro.sim.sweeps`: :func:`sweep_pairs` pads-and-masks over flight
 size and traces rho/AZ-count/overhead so a whole (flight x AZ x rho x
-load) grid shares a handful of compilations instead of paying ~1.5s of
-XLA compile per point (BENCH_sim.json), with the config axis sharded over
+load) grid shares a handful of compilations instead of paying an XLA
+compile per point, with the config axis sharded over
 the jax device mesh, and ``sequences="random"`` swaps the §3.3.3 cyclic
 shifts for per-trial random orders (the ROADMAP F>>K paper-gap probe).
 The scalar sim remains the oracle: ``tests/test_sim_vector.py`` and
@@ -236,7 +236,7 @@ def _flight_trial(z_seq, fail_seq, t_join, seq, slat, active=None,
     carry0 = (done0, attempted0, cur0, curfail0, fin0,
               jnp.array(False), jnp.array(False), jnp.array(jnp.inf))
     # unrolling removes the scan's per-step dispatch overhead — the hot
-    # path for small flights is a handful of steps (see BENCH_sim.json)
+    # path for small flights is a handful of steps
     steps = int(num_events) if num_events is not None else F * K
     (_, _, _, _, _, finished, ok, t_resp), _ = lax.scan(
         step, carry0, None, length=steps, unroll=min(steps, 8))
@@ -375,7 +375,7 @@ def _stock_batch(key, *, trials, num_tasks, dist, rho, mean, offset, cv,
 # --------------------------------------------------------------------------
 # batched config sweeps: pad-and-mask over flight size, traced rho/AZ/load
 # --------------------------------------------------------------------------
-# sweep_scale() used to pay a full XLA compile (~1.5s, BENCH_sim.json) per
+# sweep_scale() used to pay a full XLA compile per
 # (flight, num_azs, rho, load) point because every knob was a static jit
 # argument.  Here the knobs are *traced*: flights are padded to a common
 # F_pad with inactive members masked out of the event scan, the AZ index is

@@ -76,13 +76,14 @@ from __future__ import annotations
 
 import dataclasses
 import functools
-from typing import Dict, Tuple
+from typing import Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 from jax import lax
 
+from repro.core import obs
 from repro.core.analytics import summarize_batch
 from repro.core.workflow import WorkflowGraph, compile_spec, fanout, task
 from repro.sim.cluster import OverheadModel, lognormal_params
@@ -657,45 +658,46 @@ def _raptor_job_body(*, W, A, F, w_az, seq, dep_mask, slat, direct,
         # shows — see tests/test_sim_queue.py.)
         # one-hot arithmetic only — vmapped dynamic gathers/scatters
         # (w_az[w], used_az.at[az], wf.at[w]) cripple the replay
-        wf = wfree
-        fresh = jnp.ones(W, dtype=bool)      # workers in unused AZs
-        t_disp, widx, m_az = [], [], []
-        for m in range(F):
-            t_any = jnp.min(wf)
-            contended = t_any > arrival
-            free = wf <= arrival
-            elig = fresh & free
-            if fault_mode:
-                # health-aware HA: healthy beats fresh beats neither
-                # (a browned-out AZ is skipped while ANY healthy free
-                # worker exists, and placement degrades gracefully to
-                # fewer zones when brownouts leave too few healthy);
-                # random-uniform within each tier, like the non-fault
-                # ranking below
-                key = jnp.where(free, prj + 2.0 * hw + 1.0 * fresh,
-                                -1.0)
-            else:
-                # one argmax: fresh free workers rank in (1, 2], other
-                # free in (0, 1], busy at -1 — random-uniform per tier
-                key = jnp.where(elig, prj + 1.0,
-                                jnp.where(free, prj, -1.0))
-            w = jnp.where(contended, jnp.argmin(wf), jnp.argmax(key))
-            w_hot = jnp.arange(W) == w
-            az = jnp.sum(jnp.where(w_hot, w_az, 0))
-            fresh = fresh & (w_az != az)
-            t_disp.append(jnp.maximum(arrival, t_any))
-            widx.append(w)
-            m_az.append(az)
-            wf = jnp.where(w_hot, jnp.inf, wf)
-        t_disp = jnp.stack(t_disp)
-        widx = jnp.stack(widx)
-        m_az = jnp.stack(m_az)
-        # the AZ-shared S block follows the *actual* placement, so
-        # co-located members (queue pressure) re-correlate like the
-        # scalar sim; one-hot row select, no in-loop gathers
-        az_hot = jnp.arange(A)[:, None] == m_az[None, :]     # (A, F)
-        z_seq = jnp.sum(jnp.where(az_hot[:, :, None], zcj, 0.0),
-                        axis=0)
+        with obs.stage("placement"):
+            wf = wfree
+            fresh = jnp.ones(W, dtype=bool)      # workers in unused AZs
+            t_disp, widx, m_az = [], [], []
+            for m in range(F):
+                t_any = jnp.min(wf)
+                contended = t_any > arrival
+                free = wf <= arrival
+                elig = fresh & free
+                if fault_mode:
+                    # health-aware HA: healthy beats fresh beats neither
+                    # (a browned-out AZ is skipped while ANY healthy free
+                    # worker exists, and placement degrades gracefully to
+                    # fewer zones when brownouts leave too few healthy);
+                    # random-uniform within each tier, like the non-fault
+                    # ranking below
+                    key = jnp.where(free, prj + 2.0 * hw + 1.0 * fresh,
+                                    -1.0)
+                else:
+                    # one argmax: fresh free workers rank in (1, 2], other
+                    # free in (0, 1], busy at -1 — random-uniform per tier
+                    key = jnp.where(elig, prj + 1.0,
+                                    jnp.where(free, prj, -1.0))
+                w = jnp.where(contended, jnp.argmin(wf), jnp.argmax(key))
+                w_hot = jnp.arange(W) == w
+                az = jnp.sum(jnp.where(w_hot, w_az, 0))
+                fresh = fresh & (w_az != az)
+                t_disp.append(jnp.maximum(arrival, t_any))
+                widx.append(w)
+                m_az.append(az)
+                wf = jnp.where(w_hot, jnp.inf, wf)
+            t_disp = jnp.stack(t_disp)
+            widx = jnp.stack(widx)
+            m_az = jnp.stack(m_az)
+            # the AZ-shared S block follows the *actual* placement, so
+            # co-located members (queue pressure) re-correlate like the
+            # scalar sim; one-hot row select, no in-loop gathers
+            az_hot = jnp.arange(A)[:, None] == m_az[None, :]     # (A, F)
+            z_seq = jnp.sum(jnp.where(az_hot[:, :, None], zcj, 0.0),
+                            axis=0)
         if fault_mode:
             # per-member fault tables follow the actual placement
             # (one-hot row selects — same no-gather discipline as the
@@ -714,13 +716,14 @@ def _raptor_job_body(*, W, A, F, w_az, seq, dep_mask, slat, direct,
                         u_e, u_j)
         else:
             recovery = None
-        if closed_form:
-            t_resp, ok, t_rel = _race_f2k2(z_seq, t_disp + ohj)
-        else:
-            t_resp, ok, t_rel = dag_flight_trial(
-                z_seq, fj, t_disp + ohj, seq, dep_mask, slat,
-                direct_start=direct, num_events=race_events,
-                no_failures=not anyfail, recovery=recovery, cond=cond)
+        with obs.stage("race"):
+            if closed_form:
+                t_resp, ok, t_rel = _race_f2k2(z_seq, t_disp + ohj)
+            else:
+                t_resp, ok, t_rel = dag_flight_trial(
+                    z_seq, fj, t_disp + ohj, seq, dep_mask, slat,
+                    direct_start=direct, num_events=race_events,
+                    no_failures=not anyfail, recovery=recovery, cond=cond)
         # the max-fold into the free-at vector guards the flight-
         # finished-before-dispatch case (the scalar sim skips the
         # dispatch; the worker was never taken); a padded (dead) job
@@ -805,13 +808,15 @@ def _raptor_trial_fn(jobs: int, W: int, A: int, F: int,
         else:
             k_a, k_s, k_f, k_o, k_p = jax.random.split(key, 5)
             k_b = k_c = k_e = k_j = None
-        arrivals = jnp.cumsum(
-            jax.random.exponential(k_a, (jobs,)) * (1000.0 / rate_hz))
-        events = _raptor_job_draws(
-            (k_s, k_f, k_o, k_p, k_e, k_j), arrivals, W=W, A=A, F=F, K=K,
-            seq=seq, dist=dist, cv=cv, rho=rho, means=means, offset=offset,
-            stage_oh=stage_oh, oh_mu=oh_mu, oh_sigma=oh_sigma,
-            fail_prob=fail_prob, fault_mode=fault_mode, R=pol.max_retries)
+        with obs.stage("draws"):
+            arrivals = jnp.cumsum(
+                jax.random.exponential(k_a, (jobs,)) * (1000.0 / rate_hz))
+            events = _raptor_job_draws(
+                (k_s, k_f, k_o, k_p, k_e, k_j), arrivals, W=W, A=A, F=F,
+                K=K, seq=seq, dist=dist, cv=cv, rho=rho, means=means,
+                offset=offset, stage_oh=stage_oh, oh_mu=oh_mu,
+                oh_sigma=oh_sigma, fail_prob=fail_prob,
+                fault_mode=fault_mode, R=pol.max_retries)
         env = _raptor_env(fp, k_b, k_c, A, W) if fault_mode else None
         job_body = _raptor_job_body(
             W=W, A=A, F=F, w_az=w_az, seq=seq, dep_mask=dep_mask, slat=slat,
@@ -822,15 +827,14 @@ def _raptor_trial_fn(jobs: int, W: int, A: int, F: int,
             trace=trace, cond=cond)
         # no padding: the substrate resolves a ragged tail as one final
         # partial block, so phantom jobs never enter the stream
-        _, outs = blocked_event_replay(job_body, jnp.zeros(W), events,
-                                       block=block, resolver=resolver,
-                                       scan=scan,
-                                       summary_backend=summary_backend)
+        (_, passes), outs = blocked_event_replay(
+            job_body, jnp.zeros(W), events, block=block, resolver=resolver,
+            scan=scan, summary_backend=summary_backend)
         if trace:
             resp, ok, t_disp, widx, t_rel = outs
-            return resp, ok, (arrivals, t_disp, widx, t_rel)
+            return resp, ok, passes, (arrivals, t_disp, widx, t_rel)
         resp, ok = outs
-        return resp, ok
+        return resp, ok, passes
 
     return trial
 
@@ -842,7 +846,9 @@ def _raptor_stream_fns(W: int, A: int, F: int, graph: WorkflowGraph,
                        policy: RecoveryPolicy = None, block: int = 1,
                        resolver: str = "fixpoint", scan: str = "seq",
                        summary_backend: str = "xla", trace: bool = False):
-    """(draw_env, draw_events, step) for the streaming scheduler service.
+    """(draw_env, stream_draw, stream_step) for the streaming scheduler
+    service; the two jitted ones run as ``jit_stream_draw`` and
+    ``jit_stream_step``.
 
     The streaming engine (:mod:`repro.sim.streaming`) runs open arrivals
     against a *persistent* device-resident worker free-at vector: the host
@@ -856,20 +862,20 @@ def _raptor_stream_fns(W: int, A: int, F: int, graph: WorkflowGraph,
       interval processes are exogenous wall-clock tables, exactly like
       the whole-trace replay's per-trial draw).  ``None`` outside fault
       mode.
-    * ``draw_events(key, arrivals, rho, means, offset, cv, stage_oh,
+    * ``stream_draw(key, arrivals, rho, means, offset, cv, stage_oh,
       oh_mu, oh_sigma) -> events`` — the per-job event tensors for one
       microbatch of (sorted, absolute-ms) arrival times
       (:func:`_raptor_job_draws`, the same draw the whole-trace trial
       performs).  Padded (``inf``) arrivals are dead events: they book
       nothing and leave the W-state bitwise untouched.
-    * ``step(wf, events, env, slat) -> (wf', outs)`` — book one
+    * ``stream_step(wf, events, env, slat) -> (wf', outs)`` — book one
       microbatch through :func:`blocked_event_replay` with the SAME
       booking body as the whole-trace replay.  Because an event observes
       earlier events only through the carried W-vector, N consecutive
-      ``step`` calls over slices of a stream are bitwise-identical to one
-      whole-trace replay of the concatenated stream (any block/resolver/
-      scan config; tests/test_streaming.py pins this on runs AND traces,
-      faults on and off).
+      ``stream_step`` calls over slices of a stream are bitwise-identical
+      to one whole-trace replay of the concatenated stream (any block/
+      resolver/scan config; tests/test_streaming.py pins this on runs AND
+      traces, faults on and off).
     """
     fault_mode, pol, fp, anyfail = _raptor_mode(fail_prob, faults, policy)
     K = graph.K
@@ -887,7 +893,7 @@ def _raptor_stream_fns(W: int, A: int, F: int, graph: WorkflowGraph,
         k_b, k_c = jax.random.split(key)
         return _raptor_env(fp, k_b, k_c, A, W)
 
-    def draw_events(key, arrivals, rho, means, offset, cv, stage_oh,
+    def stream_draw(key, arrivals, rho, means, offset, cv, stage_oh,
                     oh_mu, oh_sigma):
         k_s, k_f, k_o, k_p, k_e, k_j = jax.random.split(key, 6)
         return _raptor_job_draws(
@@ -896,7 +902,7 @@ def _raptor_stream_fns(W: int, A: int, F: int, graph: WorkflowGraph,
             stage_oh=stage_oh, oh_mu=oh_mu, oh_sigma=oh_sigma,
             fail_prob=fail_prob, fault_mode=fault_mode, R=pol.max_retries)
 
-    def step(wf, events, env, slat):
+    def stream_step(wf, events, env, slat):
         mb = int(jax.tree_util.tree_leaves(events)[0].shape[0])
         blk = block if block else max(1, -(-mb // 3))
         race_events, closed_form = _raptor_race_budget(
@@ -908,16 +914,17 @@ def _raptor_stream_fns(W: int, A: int, F: int, graph: WorkflowGraph,
             anyfail=anyfail, fail_prob=fail_prob, pol=pol, fp=fp,
             has_failseq=(fail_prob > 0.0 and not fault_mode), env=env,
             trace=trace, cond=cond)
-        return blocked_event_replay(job_body, wf, events, block=blk,
-                                    resolver=resolver, scan=scan,
-                                    summary_backend=summary_backend)
+        (wf, _), outs = blocked_event_replay(
+            job_body, wf, events, block=blk, resolver=resolver, scan=scan,
+            summary_backend=summary_backend)
+        return wf, outs
 
     # jit HERE, inside the lru-cached factory: every StreamingScheduler
     # (and every oracle replay) of the same static config shares one
     # compiled executable instead of recompiling per engine instance.
     # The W-buffer is donated — the persistent state updates in place.
-    return (draw_env, jax.jit(draw_events),
-            jax.jit(step, donate_argnums=0))
+    return (draw_env, jax.jit(stream_draw),
+            jax.jit(stream_step, donate_argnums=0))
 
 
 @functools.lru_cache(maxsize=None)
@@ -989,8 +996,8 @@ def _stock_trial_fn(jobs: int, W: int, A: int, graph: WorkflowGraph,
     if not block:
         block = max(1, -(-Na // 3))     # adaptive log-depth split
 
-    def trial(key, rate_hz, rho, means, extras, offset, cv, stage_oh,
-              oh_mu, oh_sigma):
+    def stock_trial(key, rate_hz, rho, means, extras, offset, cv, stage_oh,
+                    oh_mu, oh_sigma):
         if fault_mode:
             (k_a, k_z, k_f, k_o,
              k_b, k_c, k_e, k_j) = jax.random.split(key, 8)
@@ -1201,7 +1208,7 @@ def _stock_trial_fn(jobs: int, W: int, A: int, graph: WorkflowGraph,
             return resp, ok, (arrivals, ready, start, fin, wkr)
         return resp, ok
 
-    return trial
+    return stock_trial
 
 
 @functools.lru_cache(maxsize=None)
@@ -1212,9 +1219,11 @@ def _raptor_runner(jobs, W, A, F, graph, dist, fail_prob,
                    scan: str = "seq", summary_backend: str = "xla",
                    trace: bool = False):
     """Jitted (trials,)-vmapped raptor runner, cached so repeated ``run()``
-    calls reuse the compiled executable.  Config sweeps no longer live
-    here: the device-sharded driver (:mod:`repro.sim.sweeps`) vmaps the
-    same per-trial body over the config axis and shards it over the mesh.
+    calls reuse the compiled executable, ``jit_trial``: per trial the
+    responses, ``ok`` bits and fixpoint pass counts per block.  Config
+    sweeps no longer live here: the device-sharded driver
+    (:mod:`repro.sim.sweeps`) vmaps the same per-trial body over the
+    config axis and shards it over the mesh.
     """
     trial = _raptor_trial_fn(jobs, W, A, F, graph, dist,
                              fail_prob, faults, policy, block, resolver,
@@ -1246,6 +1255,10 @@ class QueueResult:
     response_ms: jnp.ndarray     # (trials, jobs)
     ok: jnp.ndarray              # (trials, jobs) bool
     raptor: bool
+    # (trials, blocks) int32: the Jacobi passes each trial's blocks took
+    # (scan_core's fixpoint resolver on the "seq" chain); None on every
+    # other engine configuration.  Left on the device.
+    fixpoint_passes: Optional[jnp.ndarray] = None
 
     @property
     def jobs(self) -> int:
@@ -1327,72 +1340,74 @@ class QueueFlightSim:
         the fault branch (still block/resolver/scan invariant, bitwise);
         it is incompatible with ``booking_backend="pallas"``, whose
         fused kernel books plain FCFS finishes only."""
-        self.wl = wl
-        self.W = int(num_workers)
-        self.A = int(num_azs)
-        self.flight = int(flight if flight is not None else wl.flight)
-        if self.flight > self.W:
-            # the placement loop hands each member a distinct worker; more
-            # members than workers would dispatch at argmin(all-inf) = inf
-            raise ValueError(
-                f"flight={self.flight} needs distinct workers but the "
-                f"deployment has only num_workers={self.W}")
-        self.rho = float(rho)
-        self.load = load
-        self.slat = float(stream_latency_ms)
-        self.seed = int(seed)
-        self.rate_hz = float(
-            arrival_rate_hz if arrival_rate_hz is not None
-            else _rate_for_load(wl.work_est_ws, self.W, load))
-        # offered utilisation (service work / capacity), for reference and
-        # for sizing windows; the substrate config resolves per engine
-        self.utilization = self.rate_hz * wl.work_est_ws / self.W
-        self._block = None if block is None else int(block)
-        self.resolver = str(resolver)
-        self.scan = str(scan)
-        self.booking_backend = str(booking_backend)
-        self.summary_backend = str(summary_backend)
-        self.faults = faults if faults is not None else wl.faults
-        self.recovery = (recovery if recovery is not None
-                         else (wl.recovery if wl.recovery is not None
-                               else NO_RECOVERY))
-        # statics handed to the cached trial builders: None unless they
-        # change behavior, so disabled profiles share the pre-fault
-        # compile cache entries (and their bitwise output)
-        self._fp = (self.faults if (self.faults is not None
-                                    and self.faults.enabled) else None)
-        self.fault_mode = (self._fp is not None
-                           or not self.recovery.is_default)
-        self._policy = self.recovery if self.fault_mode else None
-        if self.fault_mode and self.booking_backend == "pallas":
-            raise ValueError(
-                "booking_backend='pallas' books plain FCFS finish times "
-                "only; fault injection needs the generic scan substrate")
-        ha = self.A > 1
-        self.oh_mu, self.oh_sigma = lognormal_params(
-            *OverheadModel.TABLE[(ha, load)])
-        # static manifest prep: both engines' sequences/masks/levels now
-        # come straight off the compiled IR (repro.core.workflow) — the
-        # graph objects themselves are the cached builders' static keys
-        self._sgraph = wl.stock_graph()
-        self._smeans = np.asarray(self._sgraph.means, dtype=np.float32)
-        self._sextras = np.asarray(wl.stock_extras(), dtype=np.float32)
-        # fixed-point pass budget for the task-FCFS stock replay: depth+1
-        # passes materialize every ready time, extras refine the estimates
-        self._sdepth = self._sgraph.stage_depth()
-        if self.fault_mode:
-            # the retry/hedge readies materialize through the same
-            # bounded fixed point as staged readies: each stage level
-            # needs its whole attempt chain resolved before dependents'
-            # estimates settle, so the pass budget scales by the
-            # per-task attempt count
-            self._spasses = ((self._sdepth + 1)
-                             * self.recovery.stock_attempts
-                             + int(stock_extra_passes))
-        else:
-            self._spasses = (1 if self._sdepth == 0
-                             else self._sdepth + 1
-                             + int(stock_extra_passes))
+        self._id = obs.next_id()
+        with obs.span("build", id=self._id):
+            self.wl = wl
+            self.W = int(num_workers)
+            self.A = int(num_azs)
+            self.flight = int(flight if flight is not None else wl.flight)
+            if self.flight > self.W:
+                # the placement loop hands each member a distinct worker; more
+                # members than workers would dispatch at argmin(all-inf) = inf
+                raise ValueError(
+                    f"flight={self.flight} needs distinct workers but the "
+                    f"deployment has only num_workers={self.W}")
+            self.rho = float(rho)
+            self.load = load
+            self.slat = float(stream_latency_ms)
+            self.seed = int(seed)
+            self.rate_hz = float(
+                arrival_rate_hz if arrival_rate_hz is not None
+                else _rate_for_load(wl.work_est_ws, self.W, load))
+            # offered utilisation (service work / capacity), for reference and
+            # for sizing windows; the substrate config resolves per engine
+            self.utilization = self.rate_hz * wl.work_est_ws / self.W
+            self._block = None if block is None else int(block)
+            self.resolver = str(resolver)
+            self.scan = str(scan)
+            self.booking_backend = str(booking_backend)
+            self.summary_backend = str(summary_backend)
+            self.faults = faults if faults is not None else wl.faults
+            self.recovery = (recovery if recovery is not None
+                             else (wl.recovery if wl.recovery is not None
+                                   else NO_RECOVERY))
+            # statics handed to the cached trial builders: None unless they
+            # change behavior, so disabled profiles share the pre-fault
+            # compile cache entries (and their bitwise output)
+            self._fp = (self.faults if (self.faults is not None
+                                        and self.faults.enabled) else None)
+            self.fault_mode = (self._fp is not None
+                               or not self.recovery.is_default)
+            self._policy = self.recovery if self.fault_mode else None
+            if self.fault_mode and self.booking_backend == "pallas":
+                raise ValueError(
+                    "booking_backend='pallas' books plain FCFS finish times "
+                    "only; fault injection needs the generic scan substrate")
+            ha = self.A > 1
+            self.oh_mu, self.oh_sigma = lognormal_params(
+                *OverheadModel.TABLE[(ha, load)])
+            # static manifest prep: both engines' sequences/masks/levels now
+            # come straight off the compiled IR (repro.core.workflow) — the
+            # graph objects themselves are the cached builders' static keys
+            self._sgraph = wl.stock_graph()
+            self._smeans = np.asarray(self._sgraph.means, dtype=np.float32)
+            self._sextras = np.asarray(wl.stock_extras(), dtype=np.float32)
+            # fixed-point pass budget for the task-FCFS stock replay: depth+1
+            # passes materialize every ready time, extras refine the estimates
+            self._sdepth = self._sgraph.stage_depth()
+            if self.fault_mode:
+                # the retry/hedge readies materialize through the same
+                # bounded fixed point as staged readies: each stage level
+                # needs its whole attempt chain resolved before dependents'
+                # estimates settle, so the pass budget scales by the
+                # per-task attempt count
+                self._spasses = ((self._sdepth + 1)
+                                 * self.recovery.stock_attempts
+                                 + int(stock_extra_passes))
+            else:
+                self._spasses = (1 if self._sdepth == 0
+                                 else self._sdepth + 1
+                                 + int(stock_extra_passes))
 
     # -- compiled runners ------------------------------------------------
     def engine_config(self, engine: str) -> Tuple[int, str, str]:
@@ -1436,18 +1451,27 @@ class QueueFlightSim:
                 wl.stock_stage_ms, self.oh_mu, self.oh_sigma)
 
     def _keys(self, trials: int, raptor: bool):
-        base = jax.random.PRNGKey(self.seed * 2 + (1 if raptor else 0))
-        return jax.random.split(base, trials)
+        with obs.span("keys", id=self._id):
+            base = jax.random.PRNGKey(self.seed * 2 + (1 if raptor else 0))
+            return jax.random.split(base, trials)
 
     def run(self, jobs: int = 1024, trials: int = 16, *,
             raptor: bool = True) -> QueueResult:
-        if raptor:
-            fn = self._raptor_fn(jobs)
-            resp, ok = fn(self._keys(trials, True), *self._raptor_args())
-        else:
-            fn = self._stock_fn(jobs)
-            resp, ok = fn(self._keys(trials, False), *self._stock_args())
-        return QueueResult(resp, ok, raptor)
+        """Dispatch ``trials`` trials of ``jobs`` arrivals; the result's
+        arrays stay on the device.  Host spans (:mod:`repro.core.obs`):
+        ``sim.build`` (the constructor), ``sim.keys`` (the trial keys) and
+        ``sim.dispatch`` (the runner's lookup and enqueue), all carrying
+        this simulator's ``id``."""
+        keys = self._keys(trials, raptor)
+        with obs.span("dispatch", id=self._id):
+            if raptor:
+                fn = self._raptor_fn(jobs)
+                resp, ok, passes = fn(keys, *self._raptor_args())
+            else:
+                fn = self._stock_fn(jobs)
+                resp, ok = fn(keys, *self._stock_args())
+                passes = None
+        return QueueResult(resp, ok, raptor, passes)
 
     def run_pair(self, jobs: int = 1024, trials: int = 16) -> Dict[str, dict]:
         stock = self.run(jobs, trials, raptor=False)
@@ -1470,7 +1494,7 @@ class QueueFlightSim:
         """
         if raptor:
             fn = self._raptor_fn(jobs, trace=True)
-            resp, ok, (arr, disp, widx, rel) = fn(
+            resp, ok, _, (arr, disp, widx, rel) = fn(
                 self._keys(trials, True), *self._raptor_args())
             return {"response": np.asarray(resp), "ok": np.asarray(ok),
                     "arrival": np.asarray(arr),
@@ -1510,7 +1534,7 @@ class QueueFlightSim:
 # axis is pure batching; repro.sim.sweeps vmaps it and shards it over the
 # device mesh (bit-identical to the single-device run) — adding a point
 # costs milliseconds, not a recompile, and a multi-device host runs the
-# grid near-linearly faster (BENCH_sim.json sweep_sharded).
+# grid near-linearly faster.
 
 def load_sweep(wl: QueueWorkload, *, num_workers: int = 15, num_azs: int = 3,
                loads=("low", "medium", "high"), rho: float = 0.95,
