@@ -316,20 +316,37 @@ def dag_flight_trial(z_seq, fail_seq, t_join, seq, dep_mask, slat,
     event mask-cancels every task gated on the opposite outcome: losers
     are marked done without consuming events, so the race budgets above
     still hold and the flight completes when the winning arm does.
+
+    The event step is one-hot arithmetic only: ``seq``, ``dep_mask`` and
+    ``cond`` are trace-time constants, so every lookup through them is a
+    select over a constant one-hot table built once outside the scan —
+    vmapped dynamic gathers (``done[seq]``, ``dep_mask[nxt]``) and the
+    re-layouts of their small (F, K) tiles cripple the replay.
     """
     F, K = z_seq.shape
     if recovery is not None:
         (r_pol, r_fp, r_base_fail, r_bs, r_be, r_cs, r_ce,
          u_err, u_jit) = recovery
+    seq_np = np.asarray(seq)
+    dep_np = np.asarray(dep_mask)
+    k_np = np.arange(K)
+    # seq_hot[f, j, k]: member f's j-th task is k, so done[seq] is
+    # any(seq_hot & done) over k
+    seq_hot = jnp.asarray(seq_np[:, :, None] == k_np)
     # dep_mask is a trace-time constant (the manifest), so a dep-free
     # workload statically elides the runnable computation below
-    has_deps = bool(np.asarray(dep_mask).any())
+    has_deps = bool(dep_np.any())
+    if has_deps:
+        # dep_seq[f, j] = dep_mask[seq[f, j]]: the deps of member f's j-th
+        # task, read at the step's one-hot next-task pick
+        dep_seq = jnp.asarray(dep_np[seq_np])
     # likewise the conditional select masks: cond=None (or all -1)
     # compiles the exact pre-conditional jaxpr
     has_cond = cond is not None and any(g >= 0 for g in cond[0])
     if has_cond:
         c_gated = jnp.array([g >= 0 for g in cond[0]])
-        c_guard = jnp.array([g if g >= 0 else 0 for g in cond[0]])
+        c_guard_hot = jnp.asarray(
+            np.maximum(np.asarray(cond[0]), 0)[:, None] == k_np)
         c_sense = jnp.array(list(cond[1]))
         c_is_guard = jnp.array(
             [k in {g for g in cond[0] if g >= 0} for k in range(K)])
@@ -375,7 +392,9 @@ def dag_flight_trial(z_seq, fail_seq, t_join, seq, dep_mask, slat,
         done2 = done | ((k_ar == task) & succ)
         if has_cond:
             # mask-select: cancel the arm gated on the opposite outcome
-            cancel = c_gated & done2[c_guard] & (outcome[c_guard] != c_sense)
+            g_done = jnp.any(c_guard_hot & done2, axis=1)
+            g_outcome = jnp.any(c_guard_hot & outcome, axis=1)
+            cancel = c_gated & g_done & (g_outcome != c_sense)
             done2 = done2 | cancel
         busy = ~jnp.isinf(fin)
         # first-success broadcast preempts peers mid-`task` (§3.3.4)
@@ -387,14 +406,18 @@ def dag_flight_trial(z_seq, fail_seq, t_join, seq, dep_mask, slat,
         # nor already attempted by this member (head-of-line: no skipping);
         # error-free attempts end only because their task completed, so
         # the attempted mask is implied by `done` and statically elided
-        cand = (~done2[seq]) if no_failures else (~done2[seq]) & ~attempted
+        cand = ~jnp.any(seq_hot & done2, axis=2)
+        if not no_failures:
+            cand &= ~attempted
         has_next = jnp.any(cand, axis=1)
         j_hot = k_ar[None, :] == jnp.argmax(cand, axis=1)[:, None]
         nxt = jnp.sum(jnp.where(j_hot, seq, 0), axis=1)
         z_next = jnp.sum(jnp.where(j_hot, z_seq, 0.0), axis=1)
         can_start = idle & has_next
         if has_deps:
-            can_start &= ~jnp.any(dep_mask[nxt] & ~done2, axis=1)
+            # unmet[f, j]: member f's j-th task still waits on a dep
+            unmet = jnp.any(dep_seq & ~done2, axis=2)
+            can_start &= ~jnp.any(j_hot & unmet, axis=1)
         # the finisher chains immediately; preempted/woken members restart
         # after the stream half-RTT
         start = jnp.where(e_hot, t, t + slat)

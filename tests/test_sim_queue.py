@@ -14,6 +14,8 @@ bit-for-bit from the source alone.  Scalar and vector seeds are chosen
 independently (the engines share no RNG stream); agreement tolerances are
 therefore statistical, sized to the windows' own run-to-run noise.
 """
+import re
+
 import numpy as np
 import pytest
 
@@ -26,7 +28,7 @@ from repro.sim.experiments import HA, LOW_AVAIL, rate_for  # noqa: E402
 from repro.sim.flights import FlightSim  # noqa: E402
 from repro.sim.vector import _flight_trial  # noqa: E402
 from repro.sim.vector_queue import (QueueFlightSim, dag_flight_trial,  # noqa: E402
-                                    keygen_queue, load_sweep,
+                                    etl_queue, keygen_queue, load_sweep,
                                     thumbnail_queue, wordcount_queue)
 from repro.sim.workloads import (keygen_workload, thumbnail_workload,  # noqa: E402
                                  wordcount_workload)
@@ -121,6 +123,23 @@ def test_dag_trial_respects_dependencies():
     assert bool(ok)
     critical = sum(float(jnp.min(z[:, j])) for j in range(K))
     assert float(t) >= critical
+
+
+@pytest.mark.parametrize("qwl_fn", [wordcount_queue, etl_queue])
+def test_race_step_compiles_without_gathers(qwl_fn):
+    """The race's event step indexes only through trace-time constants
+    (member sequences, dependency mask, conditional guards), so the
+    blocked replay's compiled program holds no gather inside the race:
+    vmapped, each gather's small (F, K) tile costs a re-layout per step.
+    ETL covers the conditional select."""
+    sim = QueueFlightSim(qwl_fn(), load="medium", seed=11, block=64,
+                         resolver="fixpoint", scan="seq", **HA)
+    text = sim._raptor_fn(128).lower(
+        sim._keys(2, True), *sim._raptor_args()).compile().as_text()
+    race = [line for line in text.splitlines()
+            if re.search(r'op_name="[^"]*race[^"]*"', line)]
+    assert race, "the race's stage scope is missing"
+    assert [line for line in race if " gather(" in line] == []
 
 
 # ------------------------------------------------------------------
